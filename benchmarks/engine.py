@@ -249,12 +249,25 @@ def check_warm_process_cold_start(n_rows: int) -> Dict[str, object]:
     against a store populated by a previous process rehydrates the
     AOT-serialized executable — no re-trace, no re-compile — and must be
     ≥ 10× faster to first KG than the cold process that populated it,
-    with the bit-identical result (sha over ``to_codes()``)."""
+    with the bit-identical result (sha over ``to_codes()``).
+
+    Each child needs the device, and a parent that has started an
+    accelerator backend holds it: so this check runs before the parent
+    touches JAX (first in :func:`run`, and ``engine`` first in
+    ``benchmarks.run``), and refuses to run after."""
     import hashlib  # noqa: F401  (used by the child)
     import os
     import subprocess
     import sys as _sys
     import tempfile
+
+    from jax._src import xla_bridge
+    if xla_bridge.backends_are_initialized() and \
+            jax.default_backend() != "cpu":
+        raise RuntimeError(
+            "check_warm_process_cold_start must run before this process "
+            f"initializes the {jax.default_backend()} backend: its child "
+            "processes need the device")
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(repo, "src")
@@ -399,7 +412,7 @@ def check_join_exchange_crossover(n_rows: int, engine: str, dedup: str,
     n_dev = jax.device_count()
     mesh = make_mesh((n_dev,), ("data",))
     # the parent must be genuinely large: the cost model's crossover sits
-    # near COLLECTIVE_LAUNCH_S · ICI_BW ≈ 100 KiB of gathered parent bytes
+    # near COLLECTIVE_LAUNCH_S · v5e ICI bandwidth ≈ 100 KiB of gathered parent bytes
     # per device (~a few thousand rows per shard)
     n_child, n_parent = max(32, n_rows // 2), max(1 << 14, 8 * n_rows)
     big = lambda: _join_heavy_dis(n_child, n_parent)  # noqa: E731
@@ -465,6 +478,9 @@ def check_join_exchange_crossover(n_rows: int, engine: str, dedup: str,
 def run(scale: float = 1.0, engine: str = "sdm", dedup: str = "hash",
         repeats: int = 3) -> List[Dict]:
     n = max(32, int(4000 * scale))
+    # first: its child processes need the device this process has not
+    # touched yet
+    warm_start = check_warm_process_cold_start(max(16, n // 4))
     rows = [
         bench_cold_vs_cached(n, engine, dedup, repeats),
         bench_ingest(n, engine, dedup, batches=max(2, repeats),
@@ -473,7 +489,7 @@ def run(scale: float = 1.0, engine: str = "sdm", dedup: str = "hash",
         check_distributed_closure_reuse(max(16, n // 4), dedup),
         check_fused_mesh_device_resident(max(16, n // 4), engine, dedup,
                                          repeats),
-        check_warm_process_cold_start(max(16, n // 4)),
+        warm_start,
         check_verifier_overhead(max(16, n // 4), engine, dedup, repeats),
     ]
     rows.extend(check_join_exchange_crossover(n, engine, dedup, repeats))

@@ -7,6 +7,12 @@ rescheduled job — restores the same checkpoint onto a 2-device mesh
 logical metadata, so restore re-device_puts each leaf with the target
 mesh's shardings.
 
+Both phases are meant for forced host (CPU) devices
+(``--xla_force_host_platform_device_count``): the example shows a restart
+onto a smaller mesh, which needs more devices than one chip has. It is not
+a chip workload; on a machine with a chip, run it with
+``JAX_PLATFORMS=cpu``.
+
 Run:  PYTHONPATH=src python examples/elastic_restart.py
 """
 import os

@@ -64,7 +64,7 @@ from repro.relalg import (PAD_ID, Table, append_rows, bucket_cap, distinct,
 
 from .cache import PLAN_CACHE, CachedPlan
 from .config import EngineConfig
-from .store import (NATIVE, STABLEHLO, deserialize_native,
+from .store import (NATIVE, STABLEHLO, compile_for_store, deserialize_native,
                     deserialize_stablehlo, pack_entry_meta, resolve_store,
                     serialize_native, serialize_stablehlo, store_envelope,
                     store_key, unpack_entry_meta)
@@ -579,7 +579,7 @@ class KGEngine:
                                safe_exchange=safe_exchange)
         if aot:
             try:
-                entry.fn = fn.lower(*abstract).compile()
+                entry.fn = compile_for_store(fn, abstract)
             except Exception:   # AOT unavailable: keep the jitted closure
                 self._store.write_errors += 1
                 aot = False
@@ -1018,7 +1018,7 @@ class KGEngine:
                                safe_exchange=safe_exchange)
         if aot:
             try:
-                entry.fn = fn.lower(*abstract).compile()
+                entry.fn = compile_for_store(fn, abstract)
             except Exception:   # AOT unavailable: keep the jitted closure
                 self._store.write_errors += 1
                 aot = False

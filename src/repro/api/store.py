@@ -75,7 +75,8 @@ MAGIC = b"RPLNSTR1"
 # v2: exchange records carry the cost-model provenance (``cost_source``)
 # and the envelope may carry a collective-bandwidth calibration tag, so
 # plans costed under measured link speeds never collide with static ones.
-FORMAT_VERSION = 2
+# v3: the native payload records the executable's device ids.
+FORMAT_VERSION = 3
 
 #: payload names inside an entry container
 NATIVE, STABLEHLO = "native", "stablehlo"
@@ -304,21 +305,54 @@ def _register_export_types() -> None:
     _export_registered = True
 
 
+def compile_for_store(fn_jit, abstract_args: Tuple):
+    """AOT-compile the closure whose executable the store will serialize.
+
+    On XLA:CPU, JAX's persistent compilation cache is bypassed for this one
+    compile: an executable the cache hands back there serializes without
+    the functions it calls, and fails when loaded again ("Function ... not
+    found"). The store is itself the persistent tier for these programs.
+    """
+    from jax._src import config as jax_config
+    from jax.experimental.compilation_cache import compilation_cache
+    lowered = fn_jit.lower(*abstract_args)
+    if jax.default_backend() != "cpu":
+        return lowered.compile()
+    # whether the cache is in use is memoized per process: reset around
+    # the toggle so the compile sees it off and later ones see it back on
+    with jax_config.enable_compilation_cache(False):
+        compilation_cache.reset_cache()
+        try:
+            return lowered.compile()
+        finally:
+            compilation_cache.reset_cache()
+
+
 def serialize_native(compiled) -> bytes:
     """Pickle the AOT-compiled executable with its calling convention
-    (:mod:`jax.experimental.serialize_executable` + the in/out treedefs)."""
+    (:mod:`jax.experimental.serialize_executable` + the in/out treedefs)
+    and the ids of the devices it was compiled for, in order."""
     from jax.experimental import serialize_executable as se
     payload, in_tree, out_tree = se.serialize(compiled)
-    return pickle.dumps((payload, in_tree, out_tree),
+    device_ids = [d.id for d in compiled.runtime_executable().local_devices()]
+    return pickle.dumps((payload, in_tree, out_tree, device_ids),
                         protocol=pickle.HIGHEST_PROTOCOL)
 
 
 def deserialize_native(blob: bytes):
     """Load a :func:`serialize_native` payload back into a callable with
-    the original positional calling convention (zero recompilation)."""
+    the original positional calling convention (zero recompilation).
+
+    The executable is loaded onto the devices it was compiled for: left
+    to its default, ``deserialize_and_load`` spreads it over every visible
+    device, and a one-device plan in a many-device process then expects
+    one argument shard per device."""
     from jax.experimental import serialize_executable as se
-    payload, in_tree, out_tree = pickle.loads(blob)
-    return se.deserialize_and_load(payload, in_tree, out_tree)
+    payload, in_tree, out_tree, device_ids = pickle.loads(blob)
+    by_id = {d.id: d for d in jax.devices()}
+    return se.deserialize_and_load(
+        payload, in_tree, out_tree,
+        execution_devices=[by_id[i] for i in device_ids])
 
 
 def serialize_stablehlo(fn_jit, abstract_args: Tuple) -> bytes:

@@ -1,30 +1,16 @@
-"""Version shims for the JAX APIs this repo uses.
+"""``shard_map`` with the repo's interpret-mode replication policy.
 
-The container pins an older jax (0.4.x) with two relevant API gaps:
-
-* ``shard_map`` still lives in ``jax.experimental.shard_map``; newer
-  releases expose it as ``jax.shard_map``;
-* the Pallas-TPU compiler-params dataclass is ``TPUCompilerParams``; newer
-  releases renamed it ``CompilerParams``.
-
-Import :data:`shard_map` / :data:`TPUCompilerParams` from here instead.
+Import :func:`shard_map` from here instead of calling ``jax.shard_map``
+directly, so every distributed body gets the same ``check_vma`` default.
 """
 from __future__ import annotations
 
 import jax
-from jax.experimental.pallas import tpu as pltpu
-
-_NEW_SHARD_MAP = hasattr(jax, "shard_map")
-if not _NEW_SHARD_MAP:  # jax < 0.6
-    from jax.experimental.shard_map import shard_map as _old_shard_map
 
 
 def shard_map(f, mesh, in_specs, out_specs, axis_names=None, check_vma=None):
-    """``jax.shard_map`` with the new keyword surface on any jax.
-
-    ``axis_names`` (manual axes; default: all mesh axes) and ``check_vma``
-    are translated for the pre-0.6 ``jax.experimental.shard_map`` signature
-    (``auto`` = complement of the manual axes, ``check_rep``).
+    """``jax.shard_map`` with ``axis_names`` (manual axes; default: all
+    mesh axes) and ``check_vma`` passed through when given.
 
     When ``REPRO_PALLAS_INTERPRET`` forces interpret-mode Pallas kernels
     into the distributed bodies (the CI interpret leg), the replication
@@ -35,31 +21,13 @@ def shard_map(f, mesh, in_specs, out_specs, axis_names=None, check_vma=None):
         from repro.kernels import pallas_interpret_forced
         if pallas_interpret_forced():
             check_vma = False
-    if _NEW_SHARD_MAP:
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    auto = frozenset()
+    kw = {}
     if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _old_shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, auto=auto,
-                          check_rep=True if check_vma is None else check_vma)
+        kw["axis_names"] = axis_names
+    if check_vma is not None:
+        kw["check_vma"] = check_vma
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
-def axis_size(name) -> int:
-    """``lax.axis_size`` on any jax (pre-0.5: the psum-of-ones identity)."""
-    from jax import lax
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(name)
-    return lax.psum(1, name)
-
-
-TPUCompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
-__all__ = ["TPUCompilerParams", "axis_size", "shard_map"]
+__all__ = ["shard_map"]

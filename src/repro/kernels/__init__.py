@@ -15,6 +15,14 @@ exactly once:
   interpreter (``pallas_call(interpret=True)``) — the CI leg that
   exercises the real kernel code on CPU runners instead of only the
   oracles. Explicit ``use_pallas=True/False`` is always honored.
+* ``pallas_interpret()`` — a kernel taken off-TPU runs interpreted; on a
+  TPU it is always the compiled Mosaic kernel, never the interpreter.
+* ``out_struct()`` — a kernel's output shape carrying the mesh axes its
+  inputs vary over, so a kernel called inside ``shard_map`` passes the
+  replication (``check_vma``) check.
+* ``dispatch_path()`` — every KG-path dispatcher reports the path it took
+  (``"compiled"``, ``"interpret"`` or ``"oracle"``) at trace time into
+  :data:`DISPATCH_COUNTS`, so which path ran is never silent.
 
 No kernel subpackage is imported here: consumers import
 ``repro.kernels.<pkg>`` directly, which keeps this module dependency-free
@@ -22,6 +30,7 @@ No kernel subpackage is imported here: consumers import
 """
 from __future__ import annotations
 
+import collections
 import os
 from typing import Optional
 
@@ -53,3 +62,25 @@ def pallas_interpret() -> bool:
     """Whether a Pallas call taken off-TPU must run interpreted (always:
     only a real TPU executes compiled Mosaic)."""
     return not on_tpu()
+
+
+#: trace-time tally of dispatcher decisions: ``{(kernel, path): n}``
+DISPATCH_COUNTS: collections.Counter = collections.Counter()
+
+
+def dispatch_path(kernel: str, use_kernel: bool) -> str:
+    """Record and return the path a dispatcher takes for one trace:
+    ``"oracle"`` when the kernel is not used, else ``"interpret"`` off-TPU
+    and ``"compiled"`` on a TPU."""
+    path = ("oracle" if not use_kernel
+            else "interpret" if pallas_interpret() else "compiled")
+    DISPATCH_COUNTS[(kernel, path)] += 1
+    return path
+
+
+def out_struct(shape, dtype, *like) -> jax.ShapeDtypeStruct:
+    """``ShapeDtypeStruct`` for a ``pallas_call`` output that varies over
+    every mesh axis any of ``like`` varies over (none outside
+    ``shard_map``)."""
+    vma = frozenset().union(*(jax.typeof(a).vma for a in like))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)

@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import TPUCompilerParams
 
 
 def _ssd_kernel(xdt_ref, la_ref, b_ref, c_ref, o_ref, fs_ref, state, *,
@@ -86,7 +85,7 @@ def mamba2_ssd_pallas(xdt: jax.Array, la: jax.Array, b: jax.Array,
         out_shape=[jax.ShapeDtypeStruct((bb * h, t, p), xdt.dtype),
                    jax.ShapeDtypeStruct((bb * h, n, p), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(xf, lf, b, c)

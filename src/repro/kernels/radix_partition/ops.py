@@ -4,8 +4,9 @@ On top of the shared backend gate (``repro.kernels.resolve_use_pallas``)
 this dispatcher applies a *feasibility* gate: the kernel keeps the whole
 bucketed output VMEM-resident and unrolls a per-bucket copy loop, so it
 only pays off (and only fits) for moderate bucket counts and output
-footprints. Infeasible shapes silently use the oracle — the two are
-bit-identical, so callers never observe which path ran.
+footprints. Infeasible shapes use the oracle — the two are bit-identical,
+so results never depend on which path ran; the choice is tallied in
+:data:`repro.kernels.DISPATCH_COUNTS` like every dispatcher's.
 """
 from __future__ import annotations
 
@@ -13,7 +14,7 @@ from typing import Optional, Tuple
 
 import jax
 
-from repro.kernels import pallas_interpret, resolve_use_pallas
+from repro.kernels import dispatch_path, resolve_use_pallas
 
 from .radix_partition import radix_partition_pallas
 from .ref import radix_partition_ref
@@ -23,20 +24,27 @@ MAX_BUCKETS = 64
 MAX_VMEM_OUT_BYTES = 6 * 2**20
 
 
+def vmem_bytes(rows: int, k: int) -> int:
+    """Bytes an int32 ``[rows, k]`` block takes in VMEM, which tiles 32-bit
+    data in (8 sublanes, 128 lanes): a 5-column row occupies 128 lanes."""
+    return -(-rows // 8) * 8 * -(-k // 128) * 128 * 4
+
+
 def kernel_feasible(n: int, k: int, n_buckets: int, cap_bucket: int,
                     block_n: int = 256) -> bool:
     """True iff the Pallas kernel supports this shape.
 
     Power-of-two bucket count >= 2 (the kernel's modulo is a bit mask),
     bounded bucket fan-out (per-bucket copy is unrolled), and the resident
-    output block must fit comfortably in VMEM.
+    output block must fit comfortably in VMEM, counted in the chip's tiled
+    layout (:func:`vmem_bytes`).
     """
     if n == 0 or k == 0:
         return False
     if n_buckets < 2 or n_buckets & (n_buckets - 1) or n_buckets > MAX_BUCKETS:
         return False
-    out_bytes = (n_buckets * cap_bucket + block_n) * k * 4
-    return out_bytes + 2 * block_n * k * 4 <= MAX_VMEM_OUT_BYTES
+    out_bytes = vmem_bytes(n_buckets * cap_bucket + block_n, k)
+    return out_bytes + vmem_bytes(2 * block_n, k) <= MAX_VMEM_OUT_BYTES
 
 
 def radix_partition(data: jax.Array, count: jax.Array, *,
@@ -55,13 +63,16 @@ def radix_partition(data: jax.Array, count: jax.Array, *,
     when a bucket's true occupancy exceeds ``cap_bucket``.
     """
     n, k = data.shape
-    if (resolve_use_pallas(use_pallas)
-            and kernel_feasible(n, k, n_buckets, cap_bucket, block_n)):
+    path = dispatch_path(
+        "radix_partition",
+        resolve_use_pallas(use_pallas)
+        and kernel_feasible(n, k, n_buckets, cap_bucket, block_n))
+    if path != "oracle":
         return radix_partition_pallas(
             data, count, n_buckets=n_buckets, cap_bucket=cap_bucket,
             key_cols=None if key_cols is None else tuple(key_cols),
             order_preserving=order_preserving, block_n=block_n,
-            interpret=pallas_interpret())
+            interpret=path == "interpret")
     return radix_partition_ref(
         data, count, n_buckets=n_buckets, cap_bucket=cap_bucket,
         key_cols=None if key_cols is None else tuple(key_cols),

@@ -38,6 +38,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import out_struct
 from repro.kernels.rowhash.ref import FNV_OFFSET, FNV_PRIME, GOLDEN
 
 from .ref import PAD_ID, bucket_shift
@@ -98,11 +99,11 @@ def _radix_partition_kernel(count_ref, x_ref, o_ref, counts_ref, tile_ref,
     onehot = (t == lax.broadcasted_iota(jnp.int32, (block_n, nb1), 1)
               ).astype(_F32)                              # [block_n, nb1]
     tile_counts = _mm(jnp.ones((1, block_n), _F32), onehot)        # [1, nb1]
-    upper = (lax.broadcasted_iota(_F32, (nb1, nb1), 0)
-             < lax.broadcasted_iota(_F32, (nb1, nb1), 1)).astype(_F32)
+    upper = (lax.broadcasted_iota(jnp.int32, (nb1, nb1), 0)
+             < lax.broadcasted_iota(jnp.int32, (nb1, nb1), 1)).astype(_F32)
     tile_offset = _mm(tile_counts, upper)                 # excl. cumsum
-    lower = (lax.broadcasted_iota(_F32, (block_n, block_n), 0)
-             > lax.broadcasted_iota(_F32, (block_n, block_n), 1)
+    lower = (lax.broadcasted_iota(jnp.int32, (block_n, block_n), 0)
+             > lax.broadcasted_iota(jnp.int32, (block_n, block_n), 1)
              ).astype(_F32)
     excl = _mm(lower, onehot)            # same-bucket predecessors per row
     rank = jnp.sum(excl * onehot, axis=1, keepdims=True)  # [block_n, 1]
@@ -111,18 +112,20 @@ def _radix_partition_kernel(count_ref, x_ref, o_ref, counts_ref, tile_ref,
                            preferred_element_type=_F32)   # [block_n, 1]
     dest = base + rank  # complete permutation of 0..block_n-1
 
-    # apply P[d, j] = (dest_j == d) via two 16-bit-limb matmuls
-    pt = (dest == lax.broadcasted_iota(_F32, (block_n, block_n), 1)
+    # apply P[d, j] = (dest_j == d) via two 16-bit-limb matmuls; the limbs
+    # stay int32 (logical shift, mask) on their way to and from f32, since
+    # the chip converts only signed integers to and from floats
+    pt = (dest.astype(jnp.int32)
+          == lax.broadcasted_iota(jnp.int32, (block_n, block_n), 1)
           ).astype(_F32)                                  # [j, d]
-    m_u = masked.astype(jnp.uint32)
-    hi = lax.shift_right_logical(m_u, jnp.uint32(16)).astype(_F32)
-    lo = (m_u & jnp.uint32(0xFFFF)).astype(_F32)
+    hi = lax.shift_right_logical(masked, jnp.int32(16)).astype(_F32)
+    lo = (masked & jnp.int32(0xFFFF)).astype(_F32)
     phi = lax.dot_general(pt, hi, (((0,), (0,)), ((), ())),
                           precision=_HIGHEST, preferred_element_type=_F32)
     plo = lax.dot_general(pt, lo, (((0,), (0,)), ((), ())),
                           precision=_HIGHEST, preferred_element_type=_F32)
-    perm = (lax.shift_left(phi.astype(jnp.uint32), jnp.uint32(16))
-            | plo.astype(jnp.uint32)).astype(jnp.int32)
+    perm = (lax.shift_left(phi.astype(jnp.int32), jnp.int32(16))
+            | plo.astype(jnp.int32))
     tile_ref[0:block_n, :] = perm
 
     # --- per-bucket blend-copy into the resident output ---
@@ -176,8 +179,8 @@ def radix_partition_pallas(data: jax.Array, count: jax.Array, *,
                   pl.BlockSpec((block_n, k), lambda i: (i, 0))],
         out_specs=(pl.BlockSpec((out_rows, k), lambda i: (0, 0)),
                    pl.BlockSpec(memory_space=pltpu.SMEM)),
-        out_shape=(jax.ShapeDtypeStruct((out_rows, k), jnp.int32),
-                   jax.ShapeDtypeStruct((n_buckets,), jnp.int32)),
+        out_shape=(out_struct((out_rows, k), jnp.int32, data, count),
+                   out_struct((n_buckets,), jnp.int32, data, count)),
         scratch_shapes=[pltpu.VMEM((2 * block_n, k), jnp.int32),
                         pltpu.SMEM((1,), jnp.int32)],
         interpret=interpret,

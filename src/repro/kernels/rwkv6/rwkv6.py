@@ -15,7 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import TPUCompilerParams
 
 
 def _rwkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, fs_ref,
@@ -80,7 +79,7 @@ def rwkv6_pallas(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
         out_shape=[jax.ShapeDtypeStruct((b * h, t, n), r.dtype),
                    jax.ShapeDtypeStruct((b * h, n, n), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(rf, kf, vf, wf, u)

@@ -59,6 +59,8 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     if not 1 <= args.shapes <= args.tenants:
         ap.error("--shapes must be in [1, --tenants]")
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
 
     mesh = None
     if args.mesh_shards:

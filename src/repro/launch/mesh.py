@@ -16,6 +16,7 @@ import json
 import math
 import os
 import time
+import warnings
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
@@ -31,12 +32,9 @@ def make_mesh(shape: Sequence[int], axes: Sequence[str],
     if len(devices) < n:
         raise ValueError(f"need {n} devices, have {len(devices)}")
     dev_array = np.asarray(devices[:n]).reshape(tuple(shape))
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:  # pre-0.5 JAX: every axis is Auto implicitly
-        return jax.sharding.Mesh(dev_array, tuple(axes))
     return jax.sharding.Mesh(
         dev_array, tuple(axes),
-        axis_types=(axis_type.Auto,) * len(axes))
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -54,11 +52,27 @@ def make_local_mesh(model: int = 1, data: Optional[int] = None
     return make_mesh((data, model), ("data", "model"))
 
 
-# TPU v5e hardware constants (per chip) — used by the roofline analysis.
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s
-HBM_BW = 819e9                  # bytes/s
-ICI_BW = 50e9                   # bytes/s per link (~ per exchange direction)
-HBM_BYTES = 16 * 2**30          # 16 GiB HBM per chip
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks of one accelerator kind."""
+    flops_bf16: float           # FLOP/s
+    hbm_bw: float               # bytes/s
+    ici_bw: float               # bytes/s per link (~ per exchange direction)
+    hbm_bytes: int              # HBM capacity
+
+
+#: ``jax.Device.device_kind`` of a TPU v5e chip
+V5E = "TPU v5 lite"
+
+#: Per-chip peaks keyed by ``device_kind``; a kind not listed has no row
+#: (``KeyError``), never a default, and callers off-TPU name ``V5E``.
+#: Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB
+#: HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect (4 links of
+#: 50 GB/s).
+PEAKS: Dict[str, ChipPeaks] = {
+    V5E: ChipPeaks(flops_bf16=197e12, hbm_bw=819e9, ici_bw=50e9,
+                   hbm_bytes=16 * 2**30),
+}
 
 
 # ---------------------------------------------------------------------------
@@ -70,7 +84,7 @@ class Calibration:
     """Per-collective bandwidth model ``t = launch_s + wire_bytes / bw``.
 
     ``source`` records provenance: ``"static"`` = the v5e datasheet
-    constants above (the cost model's default), ``"measured"`` = fitted
+    constants of :data:`PEAKS` (the cost model's default), ``"measured"`` = fitted
     from microbenchmarks on the live mesh by
     :func:`measure_collective_bandwidth`. The cost model
     (:func:`repro.plan.annotate.join_exchange_cost`) treats the two
@@ -80,6 +94,9 @@ class Calibration:
     all_to_all_bw: float        # bytes/s of per-shard wire bytes
     launch_s: float             # fixed per-collective launch cost
     source: str = "static"
+    #: why a requested measurement fell back to the static numbers
+    #: (``None`` when nothing was requested or the fit succeeded)
+    fallback: Optional[str] = None
 
     def signature(self) -> Tuple:
         """Hashable tag for plan-cache keys / store envelopes. Static
@@ -94,8 +111,15 @@ class Calibration:
 def static_calibration() -> Calibration:
     """The documented-constant cost model as a :class:`Calibration`."""
     from repro.plan.annotate import COLLECTIVE_LAUNCH_S
-    return Calibration(all_gather_bw=ICI_BW, all_to_all_bw=ICI_BW,
+    ici_bw = PEAKS[V5E].ici_bw
+    return Calibration(all_gather_bw=ici_bw, all_to_all_bw=ici_bw,
                        launch_s=COLLECTIVE_LAUNCH_S, source="static")
+
+
+def _fallback(reason: str) -> Calibration:
+    warnings.warn(f"collective calibration fell back to the static v5e "
+                  f"numbers: {reason}", RuntimeWarning, stacklevel=3)
+    return dataclasses.replace(static_calibration(), fallback=reason)
 
 
 def _fit_line(wire_bytes: Sequence[float], seconds: Sequence[float]
@@ -133,7 +157,9 @@ def measure_collective_bandwidth(mesh: jax.sharding.Mesh, axis: str, *,
     shard*: ``(n-1) · shard_bytes`` for all_gather, ``(n-1)/n · shard_bytes``
     for all_to_all. Degenerate fits (single-device axis, timer-noise-level
     payloads, non-monotone timings) fall back to the static datasheet
-    calibration rather than poisoning the cost model with a garbage slope.
+    calibration rather than poisoning the cost model with a garbage slope;
+    the returned calibration then names the cause in ``fallback`` and a
+    warning is issued, so the fallback is never passed off as a result.
     """
     from jax import lax
     from jax.sharding import PartitionSpec as P
@@ -142,7 +168,7 @@ def measure_collective_bandwidth(mesh: jax.sharding.Mesh, axis: str, *,
 
     n = int(mesh.shape[axis])
     if n < 2:
-        return static_calibration()
+        return _fallback(f"axis {axis!r} has {n} device: nothing to measure")
     cols = 128
 
     def gather_body(x):
@@ -172,7 +198,9 @@ def measure_collective_bandwidth(mesh: jax.sharding.Mesh, axis: str, *,
     g_bw, g_launch = _fit_line(g_bytes, g_secs)
     a_bw, a_launch = _fit_line(a_bytes, a_secs)
     if not (np.isfinite(g_bw) and np.isfinite(a_bw)):
-        return static_calibration()
+        return _fallback(
+            f"degenerate fit (all_gather {g_secs} s, all_to_all {a_secs} s "
+            f"for payloads {list(payload_kib)} KiB)")
     return Calibration(all_gather_bw=g_bw, all_to_all_bw=a_bw,
                        launch_s=max(g_launch, a_launch), source="measured")
 

@@ -40,7 +40,9 @@ import json
 from typing import Dict, List, Optional, Tuple
 
 from repro.configs.base import ARCH_IDS, SHAPES, get_config
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import PEAKS, V5E
+
+_V5E = PEAKS[V5E]
 
 OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "experiments", "roofline")
@@ -137,9 +139,9 @@ def analyze_cell(arch: str, shape_name: str, *, mesh: str = "single",
     params = model_param_counts(cfg)
 
     terms = {
-        "compute_s": f["flops"] / PEAK_FLOPS_BF16,
-        "memory_s": f["bytes"] / HBM_BW,
-        "collective_s": f["coll_bytes"] / ICI_BW,
+        "compute_s": f["flops"] / _V5E.flops_bf16,
+        "memory_s": f["bytes"] / _V5E.hbm_bw,
+        "collective_s": f["coll_bytes"] / _V5E.ici_bw,
     }
     dominant = max(terms, key=terms.get)
     mf = model_flops(cfg, shape, n_dev, params)
@@ -155,7 +157,7 @@ def analyze_cell(arch: str, shape_name: str, *, mesh: str = "single",
         "model_flops": mf,
         "useful_flops_ratio": (mf / f["flops"]) if f["flops"] else 0.0,
         "roofline_fraction": (
-            (mf / PEAK_FLOPS_BF16) / bound_s if bound_s else 0.0),
+            (mf / _V5E.flops_bf16) / bound_s if bound_s else 0.0),
         "params": params,
         "compile_seconds": rec0["compile_seconds"] + rec1["compile_seconds"],
         "suggestion": _suggest(dominant, terms, shape),

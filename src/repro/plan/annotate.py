@@ -50,7 +50,8 @@ Rows = Tuple[np.ndarray, Tuple[str, ...]]  # valid rows [n, k] + attr names
 #: top of wire time — the tie-breaker that keeps tiny relations on the
 #: single-collective gather plan instead of the two-exchange repartition
 #: (~dispatch latency of one ICI collective; crossover therefore sits near
-#: ``launch · ICI_BW ≈ 100 KiB`` of parent bytes per device)
+#: ``launch · ici_bw ≈ 100 KiB`` of parent bytes per device at the v5e
+#: link bandwidth of :data:`repro.launch.mesh.PEAKS`)
 COLLECTIVE_LAUNCH_S = 2e-6
 
 JOIN_EXCHANGES = ("gather", "repartition", "auto")
@@ -150,12 +151,12 @@ def join_exchange_cost(child_cap_local: int, child_cols: int,
     amortized (each ⋈'s exchange buckets are its own collectives).
     """
     from repro.core.distributed import sink_bucket_cap
-    from repro.launch.mesh import ICI_BW
+    from repro.launch.mesh import PEAKS, V5E
     if strategy not in JOIN_EXCHANGES:
         raise ValueError(f"unknown join exchange {strategy!r} "
                          f"(expected one of {JOIN_EXCHANGES})")
     if calibration is None:
-        gather_bw = a2a_bw = ICI_BW
+        gather_bw = a2a_bw = PEAKS[V5E].ici_bw
         launch_s = COLLECTIVE_LAUNCH_S
         cost_source = "static"
     else:

@@ -21,7 +21,7 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from repro.compat import axis_size, shard_map
+from repro.compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -47,7 +47,7 @@ def init_error_buffers(params) -> Any:
 def compress_allreduce(grads, err_buffers, *, axis: str = "pod"):
     """Per-pod body (inside shard_map over ``axis``): quantize+EF, psum the
     int16 payload over pods, dequantize with the mean scale."""
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
 
     def per_leaf(g, e):
         q, scale, new_e = quantize_leaf(g, e)
@@ -83,8 +83,8 @@ def hierarchical_compress_allreduce(grads, err_buffers, *,
     than the hierarchy (measured; see EXPERIMENTS.md). The EF buffers live
     on the scattered shard: shape ceil(n / |data|) per leaf
     (:func:`init_scattered_error_buffers`)."""
-    n_inner = axis_size(inner_axis)
-    n_pods = axis_size(pod_axis)
+    n_inner = lax.axis_size(inner_axis)
+    n_pods = lax.axis_size(pod_axis)
 
     def per_leaf(g, e):
         flat = g.astype(jnp.float32).ravel()

@@ -47,6 +47,10 @@ def test_ingest_extension_bit_identical_to_fresh_run(factor, seed, engine,
     dis = make_group_b_dis(24, 0.6, seed=seed)
     eng = KGEngine(dis, engine=engine, dedup=dedup)
     eng.create_kg()
+    # the plan cache is process-wide: create_kg may already have rebuilt a
+    # same-bucket plan cached by an earlier session over other data, so the
+    # ingest's own recompiles are counted from here
+    recompiles_before = eng.recompiles
     ext = make_group_b_dis(24 * factor, 0.6, seed=seed + 31)
     names = ("gene", "chrom") if both_sources else ("gene",)
     deltas = {name: _reencode(ext, name, eng.vocab,
@@ -56,7 +60,7 @@ def test_ingest_extension_bit_identical_to_fresh_run(factor, seed, engine,
     kg_ref = _oracle(dis, eng.sources, engine=engine, dedup=dedup)
     np.testing.assert_array_equal(kg.to_codes(), kg_ref.to_codes())
     # a single ingest crosses each capacity bucket at most once
-    assert stats["recompiles"] <= 1
+    assert stats["recompiles"] - recompiles_before <= 1
     # and a re-run without new data must not recompile again
     kg2, stats2 = eng.create_kg()
     assert stats2["recompiles"] == stats["recompiles"]

@@ -201,11 +201,12 @@ def test_ssd_carried_state():
 # rowhash
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("n,k", [(16, 1), (256, 3), (1000, 5), (4096, 8)])
+@pytest.mark.parametrize("n,k", [(16, 1), (256, 3), (1000, 5), (4096, 8),
+                                 (3000, 5), (8199, 2)])
 def test_rowhash_matches_ref(n, k):
     r = _rng(12)
     x = jnp.asarray(r.integers(-2**31, 2**31 - 1, (n, k)), jnp.int32)
-    got = rowhash_pallas(x, block_n=256, interpret=True)
+    got = rowhash_pallas(x, block_n=1024, interpret=True)
     ref = rowhash_ref(x)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
@@ -236,7 +237,10 @@ def test_rowhash_distribution():
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("n,k,block_n", [
+    # block_n rounds up to the 1024-row (8, 128) granule
     (64, 2, 16), (300, 4, 64), (1024, 5, 256), (257, 3, 128),
+    # several blocks, row counts off the granule
+    (3000, 4, 1024), (5000, 5, 2048), (8199, 3, 1024),
 ])
 def test_hash_neighbor_flags_matches_ref(n, k, block_n):
     from repro.kernels.rowhash.ref import hash_neighbor_flags_ref
@@ -249,6 +253,24 @@ def test_hash_neighbor_flags_matches_ref(n, k, block_n):
     ref = hash_neighbor_flags_ref(rows)
     for g, want in zip(got, ref):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(want))
+
+
+@pytest.mark.parametrize("boundary", [1024, 2048])
+def test_hash_neighbor_flags_block_boundary(boundary):
+    """A duplicate run and a real 32-bit collision pair that straddle a
+    block boundary are flagged exactly as the oracle flags them."""
+    from repro.kernels.rowhash.ref import hash_neighbor_flags_ref
+    from repro.kernels.rowhash.rowhash import hash_neighbor_flags_pallas
+    rows = _rng(22).integers(0, 1 << 20, (3 * 1024 + 5, 2)).astype(np.int32)
+    rows[boundary - 3:boundary + 2] = (9, 9)          # run across the edge
+    rows[boundary + 1023] = (573955, 771106)          # colliding pair
+    rows[boundary + 1024] = (1046201, 851388)         # across the next edge
+    rows = jnp.asarray(rows)
+    got = hash_neighbor_flags_pallas(rows, block_n=1024, interpret=True)
+    ref = hash_neighbor_flags_ref(rows)
+    for g, want in zip(got, ref):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(want))
+    assert int(ref[1][boundary]) == 0 and int(ref[2][boundary + 1024]) == 1
 
 
 def test_hash_neighbor_flags_semantics():

@@ -4,7 +4,6 @@ import subprocess
 import sys
 
 import jax
-import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -42,9 +41,6 @@ def test_param_counts_active_vs_total():
     assert d["active"] == d["total"]           # dense: all params active
 
 
-@pytest.mark.skipif(not hasattr(jax, "shard_map"),
-                    reason="partial-auto shard_map lowering needs jax>=0.6 "
-                           "(pinned 0.4.x hits PartitionId UNIMPLEMENTED)")
 def test_ef_pod_decoupled_cell_lowers():
     """grad_compress_pods=True on a non-FSDP arch: the pod-decoupled
     shard_map train step lowers + compiles on the multi-pod mesh, and the
@@ -93,6 +89,9 @@ def test_kernel_tiles_fit_vmem():
         tile = 64 * p * 4 + 2 * 64 * n * 4 + n * p * 4 + 64 * 64 * 4
         assert tile < budget, ("mamba2", p, n, tile)
 
-    # rowhash: [block_n, K] int32 rows + [block_n] u32 out, block 256
-    tile = 256 * 16 * 4 + 256 * 4
+    # rowhash / hash_neighbor_flags: K lane-dense column planes of the
+    # default 8192-row block (K <= 16), the previous block's last (8, 128)
+    # group per column, and three [block_n] 32-bit outputs
+    from repro.kernels.rowhash.rowhash import DEFAULT_BLOCK_N, ROW_TILE
+    tile = (DEFAULT_BLOCK_N * 16 + ROW_TILE * 16 + 3 * DEFAULT_BLOCK_N) * 4
     assert tile < budget, ("rowhash", tile)

@@ -178,9 +178,6 @@ print("OK", int(out.count))
 # int8 error-feedback grad compression
 # ---------------------------------------------------------------------------
 
-@pytest.mark.skipif(not hasattr(jax, "shard_map"),
-                    reason="partial-auto shard_map lowering needs jax>=0.6 "
-                           "(pinned 0.4.x hits PartitionId UNIMPLEMENTED)")
 def test_grad_compress_pod_allreduce():
     code = """
 import jax, jax.numpy as jnp, numpy as np

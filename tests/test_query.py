@@ -24,57 +24,9 @@ from repro.api import (EngineConfig, KGEngine, Query, QueryFilter,
 from repro.data.synthetic import make_group_b_dis
 from repro.plan.ir import Distinct, Scan, iter_nodes
 from repro.query import KG_SOURCE, lower_query
+from repro.query.oracle import bgp_oracle
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-# ---------------------------------------------------------------------------
-# the host-side oracle (shared with the hypothesis differential suite)
-# ---------------------------------------------------------------------------
-
-def bgp_oracle(kg, q) -> np.ndarray:
-    """Naive BGP evaluation by pattern-matching over ``kg.to_codes()`` —
-    the independent reference ``KGEngine.query`` must agree with. Returns
-    the sorted distinct answer rows as an ``[n, k]`` int array (k = the
-    width of ``q.answer_attrs()``)."""
-    rows = np.asarray(kg.to_codes())
-    kinds = q.var_kinds()
-
-    def match(binding, pat, row):
-        b = dict(binding)
-        for pos, term, cols in (("s", pat.s, (0, 1)), ("p", pat.p, (2,)),
-                                ("o", pat.o, (3, 4))):
-            val = tuple(int(row[c]) for c in cols)
-            if isinstance(term, str):
-                name = term[1:]
-                if name in b:
-                    if b[name] != val:
-                        return None
-                else:
-                    b[name] = val
-            else:
-                const = (term,) if pos == "p" else tuple(term)
-                if const != val:
-                    return None
-        return b
-
-    binds = [{}]
-    for pat in q.patterns:
-        binds = [m for b in binds for row in rows
-                 for m in (match(b, pat, row),) if m is not None]
-    for f in q.filters:
-        name = f.var[1:]
-        const = ((f.term,) if isinstance(f.term, int) else tuple(f.term))
-        binds = [b for b in binds if (b[name] == const) == (f.op == "eq")]
-    if not kinds:   # all-constant existence: the matching triple rows
-        out = sorted(set(
-            tuple(int(c) for c in row) for row in rows
-            if match({}, q.patterns[0], row) is not None))
-        return np.array(out, dtype=np.int32).reshape(len(out), 5)
-    names = q.answer_vars()
-    out = sorted(set(tuple(c for n in names for c in b[n]) for b in binds))
-    width = sum(1 if kinds[n] == "pred" else 2 for n in names)
-    return np.array(out, dtype=np.int32).reshape(len(out), width)
 
 
 def assert_query_matches_oracle(eng, kg, q):

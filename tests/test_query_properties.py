@@ -24,9 +24,8 @@ from hypothesis import HealthCheck, given, settings, strategies as st
 from repro.api import (EngineConfig, KGEngine, Query, QueryFilter,
                        TriplePattern)
 from repro.data.synthetic import make_group_b_dis
+from repro.query.oracle import bgp_oracle
 from repro.relalg import Table
-
-from test_query import bgp_oracle
 
 _SESSION = {}
 

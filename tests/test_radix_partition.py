@@ -92,6 +92,24 @@ def test_pallas_interpret_matches_ref(n, k, nb, cb, count, key_cols):
     np.testing.assert_array_equal(np.asarray(pb), np.asarray(rb))
 
 
+@pytest.mark.parametrize("order_preserving", [False, True])
+def test_pallas_interpret_full_int32_range(order_preserving):
+    """Payloads across the whole int32 range (negatives, PAD_ID) survive
+    the kernel's 16-bit-limb permutation bit for bit."""
+    data = _rows(700, 5, seed=31, lo=-2**31, hi=2**31 - 1)
+    data[::7, 2] = PAD_ID
+    data[1::7, 0] = -1
+    data = jnp.asarray(data)
+    cnt = jnp.int32(650)
+    kw = dict(n_buckets=8, cap_bucket=160, order_preserving=order_preserving)
+    rb, rc, ro = radix_partition_ref(data, cnt, **kw)
+    pb, pc, po = radix_partition_pallas(data, cnt, block_n=256,
+                                        interpret=True, **kw)
+    assert bool(po) == bool(ro)
+    np.testing.assert_array_equal(np.asarray(pc), np.asarray(rc))
+    np.testing.assert_array_equal(np.asarray(pb), np.asarray(rb))
+
+
 def test_dispatcher_matches_partition_local():
     # the production wiring: _partition_local IS the dispatcher
     data = jnp.asarray(_rows(333, 4, seed=9))
@@ -363,9 +381,11 @@ def test_static_calibration_signature():
 def test_degenerate_fit_falls_back_to_static():
     from repro.launch.mesh import (_fit_line, make_mesh,
                                    measure_collective_bandwidth)
-    # single-device axis: nothing to measure
+    # single-device axis: nothing to measure, and the result says so
     mesh = make_mesh((1,), ("data",))
-    assert measure_collective_bandwidth(mesh, "data").source == "static"
+    with pytest.warns(RuntimeWarning, match="nothing to measure"):
+        cal = measure_collective_bandwidth(mesh, "data")
+    assert cal.source == "static" and "nothing to measure" in cal.fallback
     # non-positive slope → NaN sentinel
     bw, _ = _fit_line([1e6, 2e6, 3e6], [3e-3, 2e-3, 1e-3])
     assert np.isnan(bw)
