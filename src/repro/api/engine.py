@@ -61,6 +61,7 @@ from repro.query import (KG_SOURCE, Query, annotate_query,
                          query_session_key)
 from repro.relalg import (PAD_ID, Table, append_rows, bucket_cap, distinct,
                           host_int)
+from repro.trace import span, traced
 
 from .cache import PLAN_CACHE, CachedPlan
 from .config import EngineConfig
@@ -269,29 +270,30 @@ class KGEngine:
         self.slack = float(slack)
         self.mesh, self.mesh_axis = mesh, mesh_axis
         self.jit = jit
-        self._dis = dis.copy()
-        # session view of the extensions, re-buffered into geometric
-        # capacity buckets so within-bucket ingests never change shapes
-        self._dis.sources = {name: _to_bucket(t)
-                             for name, t in dis.sources.items()}
-        self.sources: Dict[str, Table] = self._dis.sources
-        self._tstats = TransformStats()
-        t0 = time.perf_counter()
-        self._plan = (plan_mapsdi(self._dis, stats=self._tstats,
-                                  gate=self._rewrite_gate())
-                      if optimize else lower(self._dis))
-        # the session emitter is built here, over the rewritten maps, in
-        # the same order the historical paths did — vocab growth (and so
-        # every embedded code) stays bit-compatible with the old API
-        view = self._dis.copy()
-        view.maps = list(self._plan.maps)
-        self._emitter = RDFizer(view, engine, join_caps={}, dedup=dedup)
-        view.sources = {}   # the emitter never reads extensions; dropping
-        # them keeps cached closures from pinning device tables for the
-        # lifetime of the process-wide plan cache
-        self._ir_fp = fingerprint(self._plan.emits())
-        self._emit_sig = _emitter_signature(self._emitter)
-        self._plan_seconds = time.perf_counter() - t0
+        with span("engine.open", step=0):
+            self._dis = dis.copy()
+            # session view of the extensions, re-buffered into geometric
+            # capacity buckets so within-bucket ingests never change shapes
+            self._dis.sources = {name: _to_bucket(t)
+                                 for name, t in dis.sources.items()}
+            self.sources: Dict[str, Table] = self._dis.sources
+            self._tstats = TransformStats()
+            t0 = time.perf_counter()
+            self._plan = (plan_mapsdi(self._dis, stats=self._tstats,
+                                      gate=self._rewrite_gate())
+                          if optimize else lower(self._dis))
+            # the session emitter is built here, over the rewritten maps, in
+            # the same order the historical paths did — vocab growth (and so
+            # every embedded code) stays bit-compatible with the old API
+            view = self._dis.copy()
+            view.maps = list(self._plan.maps)
+            self._emitter = RDFizer(view, engine, join_caps={}, dedup=dedup)
+            view.sources = {}   # the emitter never reads extensions; dropping
+            # them keeps cached closures from pinning device tables for the
+            # lifetime of the process-wide plan cache
+            self._ir_fp = fingerprint(self._plan.emits())
+            self._emit_sig = _emitter_signature(self._emitter)
+            self._plan_seconds = time.perf_counter() - t0
         # mesh sessions keep the sharded source blocks device-resident
         # between runs, keyed by the source Table object's identity — any
         # replacement (ingest's append_rows, direct assignment) re-shards
@@ -447,6 +449,7 @@ class KGEngine:
             tuple(sorted(self._cap_locals(sources).items())),
             len(self._dis.vocab) < (1 << 16), self.join_exchange, cal_sig)
 
+    @traced("engine.key")
     def _key(self, sources: Mapping[str, Table]) -> Tuple:
         # the static configuration component comes off the EngineConfig —
         # the one input to key derivation — never off loose attributes
@@ -475,6 +478,7 @@ class KGEngine:
                     check_cse=self.optimize).raise_for_status()
         self._verify_plan_checks += 1
 
+    @traced("engine.replan")
     def _replan(self) -> None:
         """Re-lower/re-optimize after a provenance change (e.g. σ-baked
         flags dropped by :meth:`ingest`); the cache key follows the new
@@ -498,6 +502,7 @@ class KGEngine:
                            preprocessed=self._plan.preprocessed,
                            sigma_baked=self._plan.sigma_baked)
 
+    @traced("engine.build")
     def _build(self, key: Tuple, sources: Mapping[str, Table],
                mode: Optional[str] = None,
                floor_caps: Optional[Mapping] = None,
@@ -608,6 +613,7 @@ class KGEngine:
         except Exception:
             store.write_errors += 1
 
+    @traced("engine.store_load")
     def _store_load(self, key: Tuple,
                     sources: Mapping[str, Table]) -> Optional[CachedPlan]:
         """Second-tier lookup: validate, deserialize, and rehydrate a
@@ -694,7 +700,8 @@ class KGEngine:
 
     def _ensure(self, sources: Mapping[str, Table]) -> Tuple[CachedPlan, bool]:
         key = self._key(sources)
-        entry = PLAN_CACHE.get(key)
+        with span("engine.lookup"):
+            entry = PLAN_CACHE.get(key)
         hit = entry is not None
         if hit:
             self._cache_hits += 1
@@ -706,6 +713,19 @@ class KGEngine:
         self._have_plan = True
         return entry, hit
 
+    @staticmethod
+    def _execute(entry: CachedPlan, *args):
+        """Dispatch the entry's compiled closure (returns before the device
+        finishes)."""
+        with span("engine.execute"):
+            return entry.fn(*args)
+
+    @staticmethod
+    def _overflowed(flag: jax.Array) -> int:
+        """Wait for a closure's truncation flag and read it."""
+        with span("engine.overflow_check"):
+            return host_int(flag)
+
     # -- execution -----------------------------------------------------------
     def run(self, sources: Optional[Mapping[str, Table]] = None
             ) -> Tuple[Table, jax.Array]:
@@ -713,6 +733,12 @@ class KGEngine:
         sources); transparently recompiles into bigger capacities when the
         closure reports truncation. Returns ``(kg, raw_count)``."""
         sources = self.sources if sources is None else sources
+        with span("engine.run", step=self._executions + 1):
+            return self._run(sources)
+
+    __call__ = run
+
+    def _run(self, sources: Mapping[str, Table]) -> Tuple[Table, jax.Array]:
         first = not self._have_plan
         t0 = time.perf_counter()
         entry, hit = self._ensure(sources)
@@ -722,7 +748,7 @@ class KGEngine:
             kg, raw, entry, hit = self._run_mesh(entry, sources, hit)
         else:
             try:
-                kg, raw, over = entry.fn(sources)
+                kg, raw, over = self._execute(entry, sources)
             except Exception:
                 # a store-loaded executable that slipped past envelope
                 # validation but cannot actually execute here is one more
@@ -732,16 +758,16 @@ class KGEngine:
                 self._store_rejects += 1
                 hit = False
                 entry = self._build(entry.key, sources)
-                kg, raw, over = entry.fn(sources)
-            if host_int(over):
+                kg, raw, over = self._execute(entry, sources)
+            if self._overflowed(over):
                 # some buffer was truncated: re-annotate exactly against the
                 # *current* extension, grow caps monotonically, re-run — the
                 # one recompile per capacity-bucket crossing
                 hit = False   # the hit did not actually serve this execution
                 entry = self._build(entry.key, sources, mode="exact",
                                     floor_caps=entry.caps)
-                kg, raw, over = entry.fn(sources)
-                if host_int(over):  # exact caps cannot under-size
+                kg, raw, over = self._execute(entry, sources)
+                if self._overflowed(over):  # exact caps cannot under-size
                     raise RuntimeError("capacity overflow persisted after "
                                        "recompile — please report")
         exec_s = time.perf_counter() - t1
@@ -752,18 +778,17 @@ class KGEngine:
         self._kg = kg          # the device-resident KG the query tier reads
         return kg, raw
 
-    __call__ = run
-
     def create_kg(self) -> Tuple[Table, Dict[str, object]]:
         """Plan (or reuse) + execute; returns ``(KG, stats)`` with the
         Table-1-style sizes of ``mapsdi_create_kg`` plus the session's
         cache/recompile counters. ``source_rows_after`` is recounted
         against the *current* extension (a cache hit's plan-time counts
         may stem from a different same-bucket extension)."""
-        before = {k: host_int(v.count) for k, v in self.sources.items()}
-        kg, raw = self.run()
-        return kg, self._run_stats(kg, raw, source_rows_before=before,
-                                   exact_rows=True)
+        with span("engine.create_kg", step=self._executions + 1):
+            before = {k: host_int(v.count) for k, v in self.sources.items()}
+            kg, raw = self.run()
+            return kg, self._run_stats(kg, raw, source_rows_before=before,
+                                       exact_rows=True)
 
     def ingest(self, deltas: Mapping[str, Table]
                ) -> Tuple[Table, Dict[str, object]]:
@@ -780,6 +805,11 @@ class KGEngine:
         the steady-state path never re-reads the data; call
         :meth:`create_kg` when you need them recounted).
         """
+        with span("engine.ingest", step=self._executions + 1):
+            return self._ingest(deltas)
+
+    def _ingest(self, deltas: Mapping[str, Table]
+                ) -> Tuple[Table, Dict[str, object]]:
         # validate the whole batch before touching any session state, so a
         # bad name can never leave the session half-mutated
         unknown = sorted(set(deltas) - set(self.sources))
@@ -794,9 +824,10 @@ class KGEngine:
         if tainted:
             self._dis.sigma_baked -= tainted
             self._replan()
-        for name, delta in deltas.items():
-            self.sources[name] = append_rows(self.sources[name], delta)
-            self._ingested_rows += host_int(delta.count)
+        with span("engine.append"):
+            for name, delta in deltas.items():
+                self.sources[name] = append_rows(self.sources[name], delta)
+                self._ingested_rows += host_int(delta.count)
         # (the appended rows are fresh Table objects, which invalidates the
         # identity-keyed device-resident shard blocks — and, via the cache
         # key's shard-local capacity buckets, any cached closure whose
@@ -806,6 +837,7 @@ class KGEngine:
         return kg, self._run_stats(kg, raw)
 
     # -- fused distributed execution -----------------------------------------
+    @traced("engine.shard")
     def _shard_sources(self, sources: Mapping[str, Table],
                        cap_locals: Mapping[str, int]) -> Tuple[Dict, Dict]:
         """Row-shard the scanned sources onto the mesh (the input
@@ -844,7 +876,8 @@ class KGEngine:
         from repro.core.distributed import unshard_rows
         datas, counts = self._shard_sources(sources, entry.cap_locals)
         try:
-            kg_d, kg_c, raw, over, sink_over = entry.fn(datas, counts)
+            kg_d, kg_c, raw, over, sink_over = self._execute(entry, datas,
+                                                             counts)
         except Exception:
             # store-loaded mesh executable failed at call time (see run())
             if entry.origin != "store":
@@ -852,9 +885,11 @@ class KGEngine:
             self._store_rejects += 1
             hit = False
             entry = self._build(entry.key, sources)
-            kg_d, kg_c, raw, over, sink_over = entry.fn(datas, counts)
+            kg_d, kg_c, raw, over, sink_over = self._execute(entry, datas,
+                                                             counts)
         for _ in range(2):   # ≤1 capacity recompile + ≤1 sink-slack growth
-            grow_caps, grow_sink = host_int(over), host_int(sink_over)
+            grow_caps = self._overflowed(over)
+            grow_sink = self._overflowed(sink_over)
             if not (grow_caps or grow_sink):
                 break
             hit = False   # the hit did not actually serve this execution
@@ -871,15 +906,19 @@ class KGEngine:
                 floor_caps=entry.caps,
                 sink_slack=entry.sink_slack * (4.0 if grow_sink else 1.0),
                 safe_exchange=bool(grow_caps) or entry.safe_exchange)
-            kg_d, kg_c, raw, over, sink_over = entry.fn(datas, counts)
-        if host_int(over):   # exact shard-local caps cannot under-size
+            kg_d, kg_c, raw, over, sink_over = self._execute(entry, datas,
+                                                             counts)
+        if self._overflowed(over):   # exact shard-local caps cannot under-size
             raise RuntimeError("mesh capacity overflow persisted after "
                                "recompile — please report")
-        if host_int(sink_over):
+        if self._overflowed(sink_over):
             raise RuntimeError("distributed δ bucket overflow at "
                                f"slack={entry.sink_slack:g}")
-        rows = unshard_rows(kg_d, kg_c, entry.out_cap_local)   # final KG only
-        kg = distinct(Table.from_codes(rows, TRIPLE_ATTRS), dedup=self.dedup)
+        with span("engine.unshard"):
+            rows = unshard_rows(kg_d, kg_c, entry.out_cap_local)  # final KG
+        with span("engine.redistinct"):
+            kg = distinct(Table.from_codes(rows, TRIPLE_ATTRS),
+                          dedup=self.dedup)
         return kg, raw, entry, hit
 
     # -- queries -------------------------------------------------------------
@@ -1122,7 +1161,10 @@ class KGEngine:
         ``kg`` defaults to the session KG (materialized via :meth:`run` on
         first use); pass an explicit coded triple table to query something
         else (it shares the session's vocab codes by construction)."""
-        t0 = time.perf_counter()
+        with span("engine.query", step=self._executions):
+            return self._query(q, kg)
+
+    def _query(self, q: Query, kg: Optional[Table]) -> Table:
         table = self._kg_table(kg)
         qplan = lower_query(q)
         sources = {KG_SOURCE: table}
@@ -1136,14 +1178,12 @@ class KGEngine:
             entry = self._query_store_load(key, qplan, sources)
             if entry is None:
                 entry = self._build_query(key, qplan, table)
-        plan_s = time.perf_counter() - t0
-        t1 = time.perf_counter()
         if self.mesh is not None:
             result, entry, hit = self._run_query_mesh(entry, qplan, table,
                                                       hit)
         else:
             try:
-                result, over = entry.fn(sources)
+                result, over = self._execute(entry, sources)
             except Exception:
                 # store-loaded executable failed at call time (see run())
                 if entry.origin != "store":
@@ -1151,20 +1191,18 @@ class KGEngine:
                 self._q_store_rejects += 1
                 hit = False
                 entry = self._build_query(key, qplan, table)
-                result, over = entry.fn(sources)
-            if host_int(over):
+                result, over = self._execute(entry, sources)
+            if self._overflowed(over):
                 hit = False   # the hit did not actually serve this query
                 self._q_recompiles += 1
                 entry = self._build_query(key, qplan, table, mode="exact",
                                           floor_caps=entry.caps)
-                result, over = entry.fn(sources)
-                if host_int(over):  # exact caps cannot under-size
+                result, over = self._execute(entry, sources)
+                if self._overflowed(over):  # exact caps cannot under-size
                     raise RuntimeError("query capacity overflow persisted "
                                        "after recompile — please report")
         self._q_executions += 1
-        self._q_last = {"entry": entry, "cache_hit": hit,
-                        "plan_seconds": plan_s,
-                        "exec_seconds": time.perf_counter() - t1}
+        self._q_last = {"entry": entry, "cache_hit": hit}
         return result
 
     def _run_query_mesh(self, entry: CachedPlan, qplan, table: Table,
@@ -1177,29 +1215,33 @@ class KGEngine:
         from repro.core.distributed import unshard_rows
         datas, counts = self._shard_kg(table, entry.cap_locals[KG_SOURCE])
         try:
-            out_d, out_c, over = entry.fn(datas, counts)
+            out_d, out_c, over = self._execute(entry, datas, counts)
         except Exception:
             if entry.origin != "store":
                 raise
             self._q_store_rejects += 1
             hit = False
             entry = self._build_query(entry.key, qplan, table)
-            out_d, out_c, over = entry.fn(datas, counts)
-        if host_int(over):
+            out_d, out_c, over = self._execute(entry, datas, counts)
+        if self._overflowed(over):
             hit = False
             self._q_recompiles += 1
             entry = self._build_query(entry.key, qplan, table, mode="exact",
                                       floor_caps=entry.caps,
                                       safe_exchange=True)
-            out_d, out_c, over = entry.fn(datas, counts)
-            if host_int(over):   # exact caps + safe buckets cannot under-size
+            out_d, out_c, over = self._execute(entry, datas, counts)
+            if self._overflowed(over):  # exact caps + safe buckets cannot
+                # under-size
                 raise RuntimeError("mesh query capacity overflow persisted "
                                    "after recompile — please report")
-        rows = unshard_rows(out_d, out_c, entry.out_cap_local)
-        result = distinct(Table.from_codes(rows, entry.plan.out_attrs),
-                          dedup=self.dedup)
+        with span("engine.unshard"):
+            rows = unshard_rows(out_d, out_c, entry.out_cap_local)
+        with span("engine.redistinct"):
+            result = distinct(Table.from_codes(rows, entry.plan.out_attrs),
+                              dedup=self.dedup)
         return result, entry, hit
 
+    @traced("engine.shard")
     def _shard_kg(self, table: Table, cap_local: int) -> Tuple:
         """Shard the bucketed KG onto the mesh, cached on the table
         object's identity (a fresh KG from run()/ingest() re-shards)."""
@@ -1251,6 +1293,7 @@ class KGEngine:
     def vocab(self):
         return self._dis.vocab
 
+    @traced("engine.stats")
     def _run_stats(self, kg: Table, raw, source_rows_before=None,
                    exact_rows: bool = False) -> Dict[str, object]:
         entry: CachedPlan = self._last["entry"]
@@ -1340,11 +1383,6 @@ class KGEngine:
                 "store_rejects": self._q_store_rejects,
             },
         }
-        if self._last:
-            out["last_preprocess_seconds"] = self._last["plan_seconds"]
-            out["last_semantify_seconds"] = self._last["exec_seconds"]
         if self._q_last:
-            out["query"]["last_plan_seconds"] = self._q_last["plan_seconds"]
-            out["query"]["last_exec_seconds"] = self._q_last["exec_seconds"]
             out["query"]["last_cache_hit"] = self._q_last["cache_hit"]
         return out

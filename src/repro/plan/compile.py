@@ -84,75 +84,85 @@ def execute_node(node: Node, sources: Mapping[str, Table],
       single-device value (what keeps the mesh ``raw`` count exact). The
       returned table is still fitted to the node's plan-time capacity and
       flagged on truncation here.
+
+    Each operator's device work runs under a ``jax.named_scope`` of its
+    kind (``select``, ``coleq``, ``project``, ``union``, ``distinct``,
+    ``join``, ``emit``); a ⋈'s exchange runs before, outside ``join``.
     """
     hit = memo.get(node)
     if hit is not None:
         return hit
     caps = caps or {}
     kw = dict(join_exchange=join_exchange, distinct_global=distinct_global)
+
+    def child(n: Node) -> Table:
+        return execute_node(n, sources, memo, emitter, dedup, caps, overflow,
+                            **kw)
+
+    def flag(count: jax.Array, cap: Optional[int]) -> None:
+        if overflow is not None and cap is not None:
+            overflow.append(count > jnp.int32(cap))
+
+    # children are evaluated outside the node's scope, so each operation
+    # carries the scope of the one operator that produced it
     if isinstance(node, Scan):
         out = sources[node.source]
     elif isinstance(node, Project):
-        child = execute_node(node.child, sources, memo, emitter, dedup, caps,
-                             overflow, **kw)
-        out = project_as(child, list(node.spec))
+        table = child(node.child)
+        with jax.named_scope("project"):
+            out = project_as(table, list(node.spec))
     elif isinstance(node, Select):
-        child = execute_node(node.child, sources, memo, emitter, dedup, caps,
-                             overflow, **kw)
-        sel = select_mask(child, _pred_mask(child, node.preds))
-        cap = caps.get(node)
-        if overflow is not None and cap is not None:
-            overflow.append(sel.count > jnp.int32(cap))
-        out = _fit(sel, cap)
+        table = child(node.child)
+        with jax.named_scope("select"):
+            sel = select_mask(table, _pred_mask(table, node.preds))
+            cap = caps.get(node)
+            flag(sel.count, cap)
+            out = _fit(sel, cap)
     elif isinstance(node, ColEq):
-        child = execute_node(node.child, sources, memo, emitter, dedup, caps,
-                             overflow, **kw)
-        mask = child.column(node.left_attr) == child.column(node.right_attr)
-        sel = select_mask(child, mask)
-        cap = caps.get(node)
-        if overflow is not None and cap is not None:
-            overflow.append(sel.count > jnp.int32(cap))
-        out = _fit(sel, cap)
+        table = child(node.child)
+        with jax.named_scope("coleq"):
+            mask = (table.column(node.left_attr)
+                    == table.column(node.right_attr))
+            sel = select_mask(table, mask)
+            cap = caps.get(node)
+            flag(sel.count, cap)
+            out = _fit(sel, cap)
     elif isinstance(node, Distinct):
-        child = execute_node(node.child, sources, memo, emitter, dedup, caps,
-                             overflow, **kw)
-        dd = (distinct(child, dedup=dedup) if distinct_global is None
-              else distinct_global(node, child))
-        cap = caps.get(node)
-        if overflow is not None and cap is not None:
-            overflow.append(dd.count > jnp.int32(cap))
-        out = _fit(dd, cap)
+        table = child(node.child)
+        with jax.named_scope("distinct"):
+            dd = (distinct(table, dedup=dedup) if distinct_global is None
+                  else distinct_global(node, table))
+            cap = caps.get(node)
+            flag(dd.count, cap)
+            out = _fit(dd, cap)
     elif isinstance(node, Union):
-        parts = [execute_node(c, sources, memo, emitter, dedup, caps,
-                              overflow, **kw)
-                 for c in node.inputs]
-        aligned = [parts[0]] + [project(p, parts[0].attrs) for p in parts[1:]]
-        data = jnp.concatenate([_masked_data(p) for p in aligned], axis=0)
-        keep = jnp.concatenate([p.valid_mask for p in aligned])
-        data, count = compact(data, keep)
-        out = Table(data=data, count=count, attrs=parts[0].attrs)
+        parts = [child(c) for c in node.inputs]
+        with jax.named_scope("union"):
+            aligned = [parts[0]] + [project(p, parts[0].attrs)
+                                    for p in parts[1:]]
+            data = jnp.concatenate([_masked_data(p) for p in aligned],
+                                   axis=0)
+            keep = jnp.concatenate([p.valid_mask for p in aligned])
+            data, count = compact(data, keep)
+            out = Table(data=data, count=count, attrs=parts[0].attrs)
     elif isinstance(node, EquiJoin):
-        left = execute_node(node.left, sources, memo, emitter, dedup, caps,
-                            overflow, **kw)
-        right = execute_node(node.right, sources, memo, emitter, dedup, caps,
-                             overflow, **kw)
+        left, right = child(node.left), child(node.right)
         if join_exchange is not None:
             left, right = join_exchange(node, left, right)
-        cap = caps.get(node, round_cap(left.capacity * 4))
-        out, total = equi_join(left, right, node.left_key, node.right_key,
-                               out_capacity=cap,
-                               right_suffix=node.right_suffix)
-        if overflow is not None:
-            overflow.append(total > jnp.int32(cap))
+        with jax.named_scope("join"):
+            cap = caps.get(node, round_cap(left.capacity * 4))
+            out, total = equi_join(left, right, node.left_key,
+                                   node.right_key, out_capacity=cap,
+                                   right_suffix=node.right_suffix)
+            if overflow is not None:
+                overflow.append(total > jnp.int32(cap))
     elif isinstance(node, EmitTriples):
         if emitter is None:
             raise ValueError("EmitTriples node needs an emitter")
-        table = execute_node(node.input, sources, memo, emitter, dedup, caps,
-                             overflow, **kw)
-        joins = {i: execute_node(j, sources, memo, emitter, dedup, caps,
-                                 overflow, **kw)
-                 for i, j in node.joins}
-        out = emitter.emit_triples(node.tm, table, joins)
+        table = child(node.input)
+        joins = {i: child(j) for i, j in node.joins}
+        with jax.named_scope("emit"):
+            out = emitter.emit_triples(node.tm, table, joins)
     else:
         raise TypeError(f"cannot execute node {type(node).__name__}")
     memo[node] = out
@@ -200,7 +210,11 @@ def compile_plan(plan: LogicalPlan, emitter, engine: str = "rmlmapper",
     :meth:`LogicalPlan.sink`, which is what ``dump_plan``/``explain``
     display. The distributed sibling is
     :func:`repro.plan.mesh.compile_mesh_plan` (same DAG, one shard_map
-    body, the sink δ fused as a repartition collective)."""
+    body, the sink δ fused as a repartition collective).
+
+    The closure's tail runs under the scopes ``sink.distinct_per_map``
+    (sdm's per-map δ), ``sink.union`` (the merge of the maps' triples) and
+    ``sink.distinct`` (the sink δ)."""
     emit_nodes = plan.emits()
 
     def fn(sources: Mapping[str, Table]):
@@ -210,7 +224,8 @@ def compile_plan(plan: LogicalPlan, emitter, engine: str = "rmlmapper",
                                 flags)
                    for e in emit_nodes]
         if engine == "sdm":
-            per_map = [distinct(t, dedup=dedup) for t in per_map]
+            with jax.named_scope("sink.distinct_per_map"):
+                per_map = [distinct(t, dedup=dedup) for t in per_map]
         raw = jnp.sum(jnp.stack([t.count for t in per_map]))
 
         def done(kg: Table):
@@ -222,11 +237,14 @@ def compile_plan(plan: LogicalPlan, emitter, engine: str = "rmlmapper",
 
         if engine == "sdm" and len(per_map) == 1:
             return done(per_map[0])     # δδ = δ: per-map δ IS the sink δ
-        data = jnp.concatenate([t.data for t in per_map], axis=0)
-        mask = jnp.concatenate([t.valid_mask for t in per_map])
-        data, count = compact(data, mask)
-        merged = Table(data=data, count=count, attrs=per_map[0].attrs)
-        return done(distinct(merged, dedup=dedup))
+        with jax.named_scope("sink.union"):
+            data = jnp.concatenate([t.data for t in per_map], axis=0)
+            mask = jnp.concatenate([t.valid_mask for t in per_map])
+            data, count = compact(data, mask)
+            merged = Table(data=data, count=count, attrs=per_map[0].attrs)
+        with jax.named_scope("sink.distinct"):
+            kg = distinct(merged, dedup=dedup)
+        return done(kg)
 
     return jax.jit(fn) if jit else fn
 

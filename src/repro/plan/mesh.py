@@ -44,6 +44,11 @@ flag, every exchange reports its bucket-overflow flag, and the sink reports
 its own, so ``KGEngine``'s recompile-on-overflow works per shard exactly as
 on one device (``safe_exchange=True`` rebuilds with hard-safe bucket
 capacities — ``cap_bucket = cap_local`` cannot overflow).
+
+Scopes: each ⋈'s exchange runs under ``exchange`` and each global δ under
+``distinct_global``; the tail runs under ``sink.distinct_per_map``,
+``sink.union`` and ``sink.distinct``, as in
+:func:`repro.plan.compile.compile_plan`.
 """
 from __future__ import annotations
 
@@ -201,14 +206,16 @@ def compile_mesh_plan(plan: LogicalPlan, emitter, mesh, axis: str,
             return hit
 
         def join_exchange(node: Node, left: Table, right: Table):
-            if strategies.get(node) == "repartition":
-                return (exchange_table(node.left, left, node.left_key),
-                        exchange_table(node.right, right, node.right_key))
-            hit = gathered.get(node.right)
-            if hit is None:
-                hit = gathered[node.right] = gather_table(right, axis,
-                                                          n_shards)
-            return left, hit
+            with jax.named_scope("exchange"):
+                if strategies.get(node) == "repartition":
+                    return (exchange_table(node.left, left, node.left_key),
+                            exchange_table(node.right, right,
+                                           node.right_key))
+                hit = gathered.get(node.right)
+                if hit is None:
+                    hit = gathered[node.right] = gather_table(right, axis,
+                                                              n_shards)
+                return left, hit
 
         def global_distinct(table: Table, cap_bucket: int,
                             flag_list) -> Table:
@@ -221,15 +228,17 @@ def compile_mesh_plan(plan: LogicalPlan, emitter, mesh, axis: str,
             no exchange; the bucket-overflow flag lands in ``flag_list``
             (``flags`` = safe-exchange rebuild, ``sink_flags`` =
             sink-slack rebuild)."""
-            data, cnt = dedup_rows(_masked_data(table), table.count, dedup)
-            if n_shards > 1:
-                data, cnt, over = repartition_by_key(
-                    data, cnt, axis=axis, n_shards=n_shards,
-                    cap_bucket=cap_bucket, key_cols=None,
-                    pack_u16=pack_u16)
-                flag_list.append(over)
-                data, cnt = dedup_rows(data, cnt, dedup)
-            return Table(data=data, count=cnt, attrs=table.attrs)
+            with jax.named_scope("distinct_global"):
+                data, cnt = dedup_rows(_masked_data(table), table.count,
+                                       dedup)
+                if n_shards > 1:
+                    data, cnt, over = repartition_by_key(
+                        data, cnt, axis=axis, n_shards=n_shards,
+                        cap_bucket=cap_bucket, key_cols=None,
+                        pack_u16=pack_u16)
+                    flag_list.append(over)
+                    data, cnt = dedup_rows(data, cnt, dedup)
+                return Table(data=data, count=cnt, attrs=table.attrs)
 
         def distinct_global(node: Node, child: Table) -> Table:
             return global_distinct(child, _bucket_cap(child.capacity),
@@ -245,29 +254,35 @@ def compile_mesh_plan(plan: LogicalPlan, emitter, mesh, axis: str,
             # map's surviving rows end up partitioned by the SAME full-row
             # hash, so the sink δ below collapses to one local δ (no
             # second exchange).
-            per_map = [global_distinct(t, sink_bucket_cap(t.capacity,
-                                                          n_shards,
-                                                          sink_slack),
-                                       sink_flags)
-                       for t in per_map]
+            with jax.named_scope("sink.distinct_per_map"):
+                per_map = [global_distinct(t, sink_bucket_cap(t.capacity,
+                                                              n_shards,
+                                                              sink_slack),
+                                           sink_flags)
+                           for t in per_map]
         raw = jnp.sum(jnp.stack([t.count for t in per_map]))
 
-        data = jnp.concatenate([_masked_data(t) for t in per_map], axis=0)
-        mask = jnp.concatenate([t.valid_mask for t in per_map])
-        data, count = compact(data, mask)
-        if engine == "sdm":
-            # rows are rowhash-partitioned per map already: local δ = global
-            kg_data, kg_count = dedup_rows(data, count, dedup)
-            kg_count = kg_count.reshape(1)
-            sink_over = (jnp.any(jnp.stack(sink_flags)) if sink_flags
-                         else jnp.zeros((), dtype=bool)).reshape(1)
-        else:
-            # the fused sink δ: this shard's triples repartitioned by
-            # rowhash so one local δ per shard is globally correct
-            cap_bucket = sink_bucket_cap(data.shape[0], n_shards, sink_slack)
-            kg_data, kg_count, sink_over = repartition_distinct_local(
-                data, count, axis=axis, n_shards=n_shards,
-                cap_bucket=cap_bucket, pack_u16=pack_u16, dedup=dedup)
+        with jax.named_scope("sink.union"):
+            data = jnp.concatenate([_masked_data(t) for t in per_map],
+                                   axis=0)
+            mask = jnp.concatenate([t.valid_mask for t in per_map])
+            data, count = compact(data, mask)
+        with jax.named_scope("sink.distinct"):
+            if engine == "sdm":
+                # rows are rowhash-partitioned per map already: local δ =
+                # global
+                kg_data, kg_count = dedup_rows(data, count, dedup)
+                kg_count = kg_count.reshape(1)
+                sink_over = (jnp.any(jnp.stack(sink_flags)) if sink_flags
+                             else jnp.zeros((), dtype=bool)).reshape(1)
+            else:
+                # the fused sink δ: this shard's triples repartitioned by
+                # rowhash so one local δ per shard is globally correct
+                cap_bucket = sink_bucket_cap(data.shape[0], n_shards,
+                                             sink_slack)
+                kg_data, kg_count, sink_over = repartition_distinct_local(
+                    data, count, axis=axis, n_shards=n_shards,
+                    cap_bucket=cap_bucket, pack_u16=pack_u16, dedup=dedup)
         over = (jnp.any(jnp.stack(flags)) if flags
                 else jnp.zeros((), dtype=bool))
         return (kg_data, kg_count, raw.reshape(1), over.reshape(1),

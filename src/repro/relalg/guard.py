@@ -7,7 +7,8 @@ materialization. This module makes that invariant observable:
 * Every host materialization in the repo goes through :func:`host_get`
   (array) / :func:`host_int` (scalar) instead of bare ``np.asarray`` /
   ``int``. The helpers behave identically but tick any active
-  :class:`TransferLedger`.
+  :class:`TransferLedger`, and write a ``repro.sync`` span around the
+  wait into an active profiler trace (:mod:`repro.trace`).
 * :func:`count_transfers` counts device→host syncs over a region (the
   planner benchmark reports eager-vs-planned sync counts with it).
 * :func:`forbid_transfers` additionally arms ``jax.transfer_guard`` so even
@@ -22,6 +23,8 @@ from typing import Iterator, List
 
 import jax
 import numpy as np
+
+from repro.trace import span
 
 
 @dataclasses.dataclass
@@ -46,6 +49,8 @@ def host_get(x) -> np.ndarray:
     if isinstance(x, jax.Array):
         for ledger in _ACTIVE:
             ledger.tick()
+        with span("sync"):
+            return np.asarray(x)
     return np.asarray(x)
 
 
@@ -54,6 +59,8 @@ def host_int(x) -> int:
     if isinstance(x, jax.Array):
         for ledger in _ACTIVE:
             ledger.tick()
+        with span("sync"):
+            return int(x)
     return int(x)
 
 

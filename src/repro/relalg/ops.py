@@ -73,14 +73,18 @@ def _masked_data(table: Table) -> jax.Array:
 
 
 def compact(data: jax.Array, keep: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """Scatter rows with ``keep`` set to the front; return (data, count)."""
-    keep = keep.astype(jnp.int32)
-    pos = jnp.cumsum(keep) - 1                      # destination row per kept row
-    capacity = data.shape[0]
-    dest = jnp.where(keep == 1, pos, capacity)      # out-of-range => dropped
-    out = jnp.full_like(data, jnp.int32(PAD_ID)).at[dest].set(
-        data, mode="drop")
-    return out, keep.sum().astype(jnp.int32)
+    """Scatter rows with ``keep`` set to the front; return (data, count).
+
+    Its operations carry the scope ``compact`` (under the calling plan
+    operator's scope)."""
+    with jax.named_scope("compact"):
+        keep = keep.astype(jnp.int32)
+        pos = jnp.cumsum(keep) - 1                  # destination row per kept row
+        capacity = data.shape[0]
+        dest = jnp.where(keep == 1, pos, capacity)  # out-of-range => dropped
+        out = jnp.full_like(data, jnp.int32(PAD_ID)).at[dest].set(
+            data, mode="drop")
+        return out, keep.sum().astype(jnp.int32)
 
 
 def lex_sorted_rows(data: jax.Array) -> jax.Array:

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.trace import span
+
 from .encoding import PAD_ID, Vocab
 from .guard import host_get, host_int
 
@@ -100,30 +102,36 @@ class Table:
     @classmethod
     def from_codes(cls, codes: np.ndarray, attrs: Sequence[str],
                    capacity: int | None = None) -> "Table":
-        """Build from an [n, k] int32 code matrix (host)."""
-        codes = np.asarray(codes, dtype=np.int32)
-        n, k = codes.shape
-        if k != len(attrs):
-            raise ValueError("codes width != len(attrs)")
-        capacity = n if capacity is None else capacity
-        if n > capacity:
-            raise ValueError(f"{n} rows exceed capacity {capacity}")
-        data = np.full((capacity, k), PAD_ID, dtype=np.int32)
-        data[:n] = codes
-        return cls(data=jnp.asarray(data), count=jnp.int32(n),
-                   attrs=tuple(attrs))
+        """Build from an [n, k] int32 code matrix (host): padded to
+        ``capacity`` on the host (span ``table.pad``), then transferred
+        (span ``table.put``)."""
+        with span("table.from_codes"):
+            codes = np.asarray(codes, dtype=np.int32)
+            n, k = codes.shape
+            if k != len(attrs):
+                raise ValueError("codes width != len(attrs)")
+            capacity = n if capacity is None else capacity
+            if n > capacity:
+                raise ValueError(f"{n} rows exceed capacity {capacity}")
+            with span("table.pad"):
+                data = np.full((capacity, k), PAD_ID, dtype=np.int32)
+                data[:n] = codes
+            with span("table.put"):
+                return cls(data=jnp.asarray(data), count=jnp.int32(n),
+                           attrs=tuple(attrs))
 
     @classmethod
     def from_records(cls, records: Iterable[Mapping[str, object]],
                      attrs: Sequence[str], vocab: Vocab,
                      capacity: int | None = None) -> "Table":
         """Intern host records (list of dicts) into a device table."""
-        rows: List[List[int]] = []
-        for rec in records:
-            rows.append([vocab.intern(rec[a]) for a in attrs])
-        codes = (np.asarray(rows, dtype=np.int32)
-                 if rows else np.zeros((0, len(attrs)), np.int32))
-        return cls.from_codes(codes, attrs, capacity)
+        with span("table.from_records"):
+            rows: List[List[int]] = []
+            for rec in records:
+                rows.append([vocab.intern(rec[a]) for a in attrs])
+            codes = (np.asarray(rows, dtype=np.int32)
+                     if rows else np.zeros((0, len(attrs)), np.int32))
+            return cls.from_codes(codes, attrs, capacity)
 
     # -- host-side views (tests / sinks only) ---------------------------------
     def to_codes(self) -> np.ndarray:
