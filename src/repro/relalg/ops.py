@@ -73,18 +73,28 @@ def _masked_data(table: Table) -> jax.Array:
 
 
 def compact(data: jax.Array, keep: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """Scatter rows with ``keep`` set to the front; return (data, count).
+    """Move rows with ``keep`` set to the front, in their order; return
+    (data, count), with every row from ``count`` on all PAD_ID.
+
+    One single-key sort, no scatter and no gather: a kept row's key is its
+    index, a dropped row's key is the capacity, so kept rows sort first in
+    their original order (their keys are unique, so the sort need not be
+    stable) and dropped rows behind them, where the tail mask overwrites
+    them. The columns ride along as 1-D payload operands. On the TPU v5e
+    this is 15–20× faster than a row scatter to cumsum destinations and 2–3×
+    faster than a key-only sort plus a row gather (``docs/relalg.md`` §1).
 
     Its operations carry the scope ``compact`` (under the calling plan
     operator's scope)."""
     with jax.named_scope("compact"):
-        keep = keep.astype(jnp.int32)
-        pos = jnp.cumsum(keep) - 1                  # destination row per kept row
-        capacity = data.shape[0]
-        dest = jnp.where(keep == 1, pos, capacity)  # out-of-range => dropped
-        out = jnp.full_like(data, jnp.int32(PAD_ID)).at[dest].set(
-            data, mode="drop")
-        return out, keep.sum().astype(jnp.int32)
+        capacity, k = data.shape
+        idx = jnp.arange(capacity, dtype=jnp.int32)
+        key = jnp.where(keep.astype(bool), idx, jnp.int32(capacity))
+        key, *cols = lax.sort((key, *(data[:, c] for c in range(k))),
+                              dimension=0, num_keys=1, is_stable=False)
+        out = jnp.where((key < capacity)[:, None], jnp.stack(cols, axis=1),
+                        jnp.asarray(PAD_ID, data.dtype))
+        return out, keep.astype(jnp.int32).sum()
 
 
 def lex_sorted_rows(data: jax.Array) -> jax.Array:
@@ -172,7 +182,7 @@ def distinct_rows(data: jax.Array, count: jax.Array
     distributed dedup (which works on raw row matrices inside shards).
 
     Lexicographic full-row sort (:func:`lex_sorted_rows`), then
-    first-occurrence compaction: a neighbour compare and a cumsum scatter.
+    first-occurrence compaction: a neighbour compare and :func:`compact`.
     Always exact; also the collision fallback of
     :func:`distinct_rows_hashed`.
     """

@@ -1,4 +1,6 @@
 """Unit + property tests for the relational-algebra substrate."""
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -7,8 +9,8 @@ pytest.importorskip("hypothesis", reason="test extra: pip install -r "
                     "test_dedup_strategies.py)")
 from hypothesis import given, settings, strategies as st
 
-from repro.relalg import (PAD_ID, Table, Vocab, distinct, equi_join, project,
-                          rename, select_eq, union)
+from repro.relalg import (PAD_ID, Table, Vocab, compact, distinct, equi_join,
+                          project, rename, select_eq, union)
 
 
 def _table(rows, attrs, capacity=None):
@@ -118,6 +120,26 @@ def test_equi_join_no_matches():
     right = _table([[2, 0]], ["k", "rv"], capacity=4)
     out, total = equi_join(left, right, "k", "k", out_capacity=4)
     assert int(total) == 0 and int(out.count) == 0
+
+
+@pytest.mark.parametrize("share", [0.0, 0.05, 0.3, 1.0])
+@pytest.mark.parametrize("capacity", [1, 7, 4096, 4099, 100_003])
+def test_compact_matches_numpy(capacity, share):
+    """Kept rows first in their order, then an all-PAD_ID tail, and the
+    count: with codes at both ends of the range and rows whose content is
+    all PAD_ID (kept or dropped alike)."""
+    rng = np.random.default_rng(capacity * 100 + int(share * 100))
+    data = rng.integers(0, PAD_ID, size=(capacity, 5), dtype=np.int32)
+    ends = rng.random(data.shape) < 0.2
+    data[ends] = rng.choice(np.array([0, PAD_ID - 1], np.int32),
+                            size=int(ends.sum()))
+    data[rng.random(capacity) < 0.1] = PAD_ID
+    keep = rng.random(capacity) < share
+    want = np.full_like(data, PAD_ID)
+    want[:keep.sum()] = data[keep]
+    out, count = jax.jit(compact)(jnp.asarray(data), jnp.asarray(keep))
+    assert int(count) == int(keep.sum())
+    np.testing.assert_array_equal(np.asarray(out), want)
 
 
 # ---------------------------------------------------------------------------
