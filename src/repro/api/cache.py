@@ -111,11 +111,21 @@ class PlanCache:
 #: process-wide cache shared by every :class:`~repro.api.KGEngine` session
 PLAN_CACHE = PlanCache()
 
+#: the jitted δ that ends every mesh run over the gathered rows
+#: (``KGEngine._redistinct``): one program per ``(capacity, width, dedup)``,
+#: shared by every session. Never evicted, so its size is the number of
+#: programs built since the last :func:`clear_plan_cache`.
+REDISTINCT: Dict[Tuple[int, int, Optional[str]], Callable] = {}
+
 
 def clear_plan_cache() -> None:
-    """Drop every cached plan (benchmarks use this to measure cold paths)."""
+    """Drop every cached plan and re-δ program (benchmarks use this to
+    measure cold paths)."""
     PLAN_CACHE.clear()
+    REDISTINCT.clear()
 
 
 def plan_cache_stats() -> Dict[str, int]:
-    return PLAN_CACHE.stats()
+    """The plan cache's hits, misses and size, and ``redistinct_builds``:
+    the mesh re-δ programs built since the last :func:`clear_plan_cache`."""
+    return dict(PLAN_CACHE.stats(), redistinct_builds=len(REDISTINCT))

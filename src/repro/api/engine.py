@@ -59,11 +59,11 @@ from repro.plan.lower import LogicalPlan, lower
 from repro.query import (KG_SOURCE, Query, annotate_query,
                          annotate_query_local, compile_query, lower_query,
                          query_session_key)
-from repro.relalg import (PAD_ID, Table, append_rows, bucket_cap, distinct,
+from repro.relalg import (PAD_ID, Table, append_rows, bucket_cap, dedup_rows,
                           host_int)
 from repro.trace import span, traced
 
-from .cache import PLAN_CACHE, CachedPlan
+from .cache import PLAN_CACHE, REDISTINCT, CachedPlan
 from .config import EngineConfig
 from .store import (NATIVE, STABLEHLO, compile_for_store, deserialize_native,
                     deserialize_stablehlo, pack_entry_meta, resolve_store,
@@ -99,6 +99,15 @@ def _to_bucket(table: Table) -> Table:
     pad = jnp.full((cap - table.capacity, table.n_attrs), jnp.int32(PAD_ID))
     return Table(data=jnp.concatenate([table.data, pad], axis=0),
                  count=table.count, attrs=table.attrs)
+
+
+def _redistinct_program(dedup: Optional[str]):
+    """The jitted δ of a gathered mesh result, under the device scope
+    ``redistinct``."""
+    def redistinct(data: jax.Array, count: jax.Array):
+        with jax.named_scope("redistinct"):
+            return dedup_rows(data, count, dedup)
+    return jax.jit(redistinct)
 
 
 def _emitter_signature(emitter: RDFizer) -> Tuple:
@@ -916,10 +925,28 @@ class KGEngine:
                                f"slack={entry.sink_slack:g}")
         with span("engine.unshard"):
             rows = unshard_rows(kg_d, kg_c, entry.out_cap_local)  # final KG
-        with span("engine.redistinct"):
-            kg = distinct(Table.from_codes(rows, TRIPLE_ATTRS),
-                          dedup=self.dedup)
+        kg = self._redistinct(rows, TRIPLE_ATTRS)
         return kg, raw, entry, hit
+
+    def _redistinct(self, rows: np.ndarray, attrs: Tuple[str, ...]
+                    ) -> Table:
+        """δ of a mesh result gathered on the host: puts its rows in the
+        canonical order of the single-device plan, which ends in the same
+        δ. The rows are padded to their capacity bucket, so one jitted
+        program per ``(capacity, width, dedup)``
+        (:data:`repro.api.cache.REDISTINCT`) serves every run in a bucket.
+        (The mesh closure's output capacity, ``out_cap_local`` per shard,
+        is the bound-mode sink's, several times the KG: padding to it
+        would multiply the δ's work.)"""
+        with span("engine.redistinct"):
+            table = Table.from_codes(rows, attrs,
+                                     capacity=bucket_cap(len(rows)))
+            key = (table.capacity, table.n_attrs, self.dedup)
+            fn = REDISTINCT.get(key)
+            if fn is None:
+                fn = REDISTINCT[key] = _redistinct_program(self.dedup)
+            data, count = fn(table.data, table.count)
+        return Table(data=data, count=count, attrs=table.attrs)
 
     # -- queries -------------------------------------------------------------
     def _kg_table(self, kg: Optional[Table]) -> Table:
@@ -1236,9 +1263,7 @@ class KGEngine:
                                    "after recompile — please report")
         with span("engine.unshard"):
             rows = unshard_rows(out_d, out_c, entry.out_cap_local)
-        with span("engine.redistinct"):
-            result = distinct(Table.from_codes(rows, entry.plan.out_attrs),
-                              dedup=self.dedup)
+        result = self._redistinct(rows, entry.plan.out_attrs)
         return result, entry, hit
 
     @traced("engine.shard")

@@ -272,3 +272,135 @@ print("OK", int(kg_mesh.count))
 """
     out = _run_with_devices(8, code)
     assert "OK" in out
+
+
+# ---------------------------------------------------------------------------
+# the re-δ of a gathered mesh result: one jitted program per shape
+# ---------------------------------------------------------------------------
+
+def group_b_transcripts(n_rows: int, seed: int):
+    """``make_group_b_dis`` with ``TripleMap1``'s subject map keyed by the
+    gene source's transcript (``Transcript/{enst}``), so the KG grows with
+    the rows and holds one ⋈ triple per matched transcript."""
+    src = make_group_b_dis(n_rows, 0.75, seed=seed)
+    spec = {"sources": {name: {"attrs": list(t.attrs),
+                               "records": t.to_records(src.vocab)}
+                        for name, t in src.sources.items()},
+            "maps": [
+                {"name": "TripleMap1", "source": "gene",
+                 "subject": {"template":
+                             "http://project-iasis.eu/Transcript/{enst}",
+                             "class": "iasis:Transcript"},
+                 "poms": [{"predicate": "iasis:isRelatedTo",
+                           "object": {"parentTriplesMap": "TripleMap2",
+                                      "joinCondition": {
+                                          "child": "Genename",
+                                          "parent": "Genename"}}}]},
+                {"name": "TripleMap2", "source": "chrom",
+                 "subject": {"template": "http://project-iasis.eu/"
+                                         "Chromosome/{Chromosome}",
+                             "class": "iasis:Chromosome"},
+                 "poms": []}]}
+    return parse_dis(spec)
+
+
+def test_redistinct_program_cached_and_cleared():
+    from repro.api import EngineConfig, clear_plan_cache, plan_cache_stats
+    clear_plan_cache()
+    config = EngineConfig(mesh=_mesh())
+    kg0, _ = KGEngine(make_group_b_dis(64, 0.6, seed=26),
+                      config=config).create_kg()
+    assert plan_cache_stats()["redistinct_builds"] == 1
+    kg1, _ = KGEngine(make_group_b_dis(64, 0.6, seed=26),
+                      config=config).create_kg()
+    assert plan_cache_stats()["redistinct_builds"] == 1
+    np.testing.assert_array_equal(kg1.to_codes(), kg0.to_codes())
+    clear_plan_cache()
+    assert plan_cache_stats()["redistinct_builds"] == 0
+
+
+_REDISTINCT_ONCE = """
+import sys
+import jax, numpy as np
+sys.path.insert(0, {testdir!r})
+from test_mesh_plan import group_b_transcripts
+from repro.api import (EngineConfig, KGEngine, Query, TriplePattern,
+                       plan_cache_stats)
+from repro.launch.mesh import make_mesh
+from repro.relalg import Table
+compiles = []
+jax.monitoring.register_event_duration_secs_listener(
+    lambda e, s, **k: compiles.append(e)
+    if e == "/jax/core/compile/backend_compile_duration" else None)
+config = EngineConfig(mesh=make_mesh((4,), ("data",)), mode="bound")
+base = group_b_transcripts(400, seed=41)
+def session(seed):
+    # a new dump of both sources, coded against one vocabulary
+    dis = base.copy()
+    other = group_b_transcripts(400, seed=seed)
+    dis.sources = {{n: Table.from_records(t.to_records(other.vocab),
+                                          t.attrs, dis.vocab)
+                   for n, t in other.sources.items()}}
+    return KGEngine(dis, config=config)
+builds = []
+for seed in (42, 43):
+    kg, _ = session(seed).create_kg()
+    builds.append(plan_cache_stats()["redistinct_builds"])
+n = len(compiles)
+eng = session(42)
+kg, _ = eng.create_kg()
+builds.append(plan_cache_stats()["redistinct_builds"])
+assert builds == [1, 1, 1], builds
+assert len(compiles) == n, "a rebuild in a warm bucket compiled"
+q = Query(patterns=[TriplePattern("?s", "?p", "?o")])
+ans = eng.query(q)
+after_first = plan_cache_stats()["redistinct_builds"]
+n = len(compiles)
+again = eng.query(q)
+assert plan_cache_stats()["redistinct_builds"] == after_first
+assert len(compiles) == n, "a repeated mesh query compiled"
+np.testing.assert_array_equal(again.to_codes(), ans.to_codes())
+print("OK", builds, after_first)
+"""
+
+
+def test_multi_device_redistinct_built_once_per_bucket():
+    """New mesh sessions over new dumps in one bucket build the re-δ
+    program once, and a warm rebuild or a repeated mesh query compiles
+    nothing."""
+    out = _run_with_devices(4, _REDISTINCT_ONCE.format(
+        testdir=os.path.join(REPO, "tests")))
+    assert "OK [1, 1, 1]" in out
+
+
+_TRANSCRIPTS_EXACT = """
+import sys
+import numpy as np
+sys.path.insert(0, {testdir!r})
+from test_mesh_plan import group_b_transcripts
+from repro.api import EngineConfig, KGEngine
+from repro.core.rdfizer import RDFizer
+from repro.core.transform import apply_mapsdi_eager
+from repro.launch.mesh import make_mesh
+dedup = {dedup!r}
+mk = lambda: group_b_transcripts(800, seed=44)
+single, _ = KGEngine(mk(), config=EngineConfig(dedup=dedup)).create_kg()
+mesh, _ = KGEngine(mk(), config=EngineConfig(
+    dedup=dedup, mesh=make_mesh((4,), ("data",)), mode="bound",
+    join_exchange="auto")).create_kg()
+np.testing.assert_array_equal(mesh.to_codes(), single.to_codes())
+pre, _ = apply_mapsdi_eager(mk(), dedup=dedup)
+eager, _ = RDFizer(pre, "sdm", dedup=dedup)()
+assert mesh.row_set() == eager.row_set()
+print("OK", int(mesh.count))
+"""
+
+
+@pytest.mark.parametrize("dedup", ["hash", "lex"])
+def test_multi_device_transcript_group_b_matches_single_device(dedup):
+    """The Group B mapping keyed by transcript: the 4-device mesh KG is
+    bit-identical to the single-device KG and holds the eager oracle's
+    rows; it grows with the rows (about one ⋈ triple per row)."""
+    out = _run_with_devices(4, _TRANSCRIPTS_EXACT.format(
+        testdir=os.path.join(REPO, "tests"), dedup=dedup))
+    assert int(out.split("OK ")[1]) > 800
